@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.jitcache import enable_compile_cache
 from repro.obs import clock
 
 ROWS: list[tuple[str, object, str]] = []
@@ -1064,6 +1065,7 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     names = [args.only] if args.only else list(BENCHES)
     print("name,value,derived")
     for name in names:

@@ -50,6 +50,10 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}
+# the CPU recipe: the smokes below import JAX in a parent and Popen a
+# server child, which on a TPU host would find the chip taken. The chip
+# path is chip_smoke.py, one process.
+export JAX_PLATFORMS=cpu
 
 echo "== static analysis: repro.analysis (4 passes, empty baseline) =="
 # replaces the old grep-based perf_counter lint: the host-sync pass owns

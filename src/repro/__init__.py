@@ -1,14 +1,1 @@
-"""NBL reproduction package.
-
-Version shim: jax >= 0.5 defaults to the partitionable threefry PRNG,
-making random values invariant to how the generating computation is
-sharded (sharded init == single-device init). Older jax defaults it off —
-turn it on so the distributed parity tests (and sharded init generally)
-are bit-stable across meshes.
-"""
-import jax
-
-try:
-    jax.config.update("jax_threefry_partitionable", True)
-except AttributeError:  # flag removed once it became the only behavior
-    pass
+"""NBL reproduction package."""

@@ -2,10 +2,11 @@
 
 All model code calls ``constrain(x, "axis0", "axis1", ...)`` with *logical*
 axis names; the helper resolves them against the ambient mesh (set by
-``with mesh:`` / ``jax.set_mesh`` around the jit) and silently drops axes the
-mesh does not have. This lets the same model run un-meshed on one CPU device
-(smoke tests), on the (data, model) single-pod mesh, and on the
-(pod, data, model) multi-pod mesh without code changes.
+``jax.set_mesh`` around the jit) and silently drops axes the mesh does not
+have. This lets the same model run un-meshed on one device (smoke tests),
+on the (data, model) single-pod mesh, and on the (pod, data, model)
+multi-pod mesh without code changes. Jits take raw PartitionSpecs for
+their in/out shardings; ``jax.set_mesh`` resolves them.
 
 Logical axis conventions:
   "dp"    -> sharded over ("pod", "data") (whichever exist)
@@ -14,8 +15,7 @@ Logical axis conventions:
 """
 from __future__ import annotations
 
-import contextlib
-from typing import Optional, Sequence, Union
+from typing import Sequence, Union
 
 import jax
 from jax.sharding import PartitionSpec as P  # noqa: F401
@@ -26,82 +26,9 @@ _DP = ("pod", "data")
 
 
 def ambient_mesh():
-    """The mesh the surrounding code entered, or None. jax >= 0.5 tracks an
-    abstract mesh via ``jax.set_mesh``; older jax tracks the physical mesh
-    entered with ``with mesh:`` — ``use_mesh`` papers over the difference.
-    Checks both trackers so intermediate jax versions (one API present,
-    the other not) still resolve whatever the caller entered."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        m = get()
-        if m is not None and getattr(m, "axis_names", ()):
-            return m
-    try:
-        from jax._src.mesh import thread_resources
-    except ImportError:  # pragma: no cover - future jax dropping the module
-        return None
-    m = thread_resources.env.physical_mesh
+    """The mesh ``jax.set_mesh`` made ambient, or None."""
+    m = jax.sharding.get_abstract_mesh()
     return None if m.empty else m
-
-
-def use_mesh(mesh):
-    """Context manager making ``mesh`` ambient (version-portable
-    ``jax.set_mesh``)."""
-    set_mesh = (getattr(jax, "set_mesh", None)
-                or getattr(jax.sharding, "use_mesh", None))
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    # oldest fallback: Mesh is itself a context manager
-    return contextlib.nullcontext() if mesh is None else mesh
-
-
-def shard_map(f, *, mesh=None, in_specs, out_specs, axis_names=None,
-              check_vma: bool = True):
-    """Version-portable ``jax.shard_map``. jax >= 0.5 takes keyword mesh /
-    ``axis_names`` (manual axes) / ``check_vma``; older jax exposes
-    ``jax.experimental.shard_map.shard_map(f, mesh, ..., check_rep, auto)``
-    — ``auto`` being the complement of ``axis_names``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return sm(f, **kw)
-    from jax.experimental.shard_map import shard_map as esm
-    m = mesh if mesh is not None else ambient_mesh()
-    if m is None:
-        raise ValueError("shard_map needs a mesh (pass mesh= or enter one "
-                         "via use_mesh)")
-    auto = (frozenset(m.axis_names) - frozenset(axis_names)
-            if axis_names is not None else frozenset())
-    return esm(f, m, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma and not auto, auto=auto)
-
-
-def jit_shardings(tree, mesh=None):
-    """Make a PartitionSpec pytree acceptable to ``jax.jit``'s
-    in/out_shardings. jax >= 0.5 accepts raw specs (resolved against the
-    ambient mesh); older jax requires NamedSharding — resolve against
-    ``mesh`` or the ambient one. None leaves (= infer) pass through, as
-    does everything when the ambient mesh is abstract (new-jax tracker:
-    raw specs are accepted there)."""
-    if hasattr(jax, "set_mesh"):
-        return tree
-    m = mesh if mesh is not None else ambient_mesh()
-    if m is None or not isinstance(m, jax.sharding.Mesh):
-        return tree
-    from jax.sharding import NamedSharding
-    return jax.tree.map(
-        lambda s: NamedSharding(m, s) if isinstance(s, P) else s,
-        tree, is_leaf=lambda x: isinstance(x, P))
-
-
-def _axis_sizes(m) -> dict:
-    sizes = getattr(m, "axis_sizes", None)
-    if sizes is not None:
-        return dict(zip(m.axis_names, sizes))
-    return dict(m.shape)
 
 
 def mesh_axes() -> tuple[str, ...]:
@@ -138,7 +65,7 @@ def axis_size(name: str) -> int:
     m = ambient_mesh()
     if m is None or name not in m.axis_names:
         return 1
-    return _axis_sizes(m)[name]
+    return m.shape[name]
 
 
 def _prod_size(resolved) -> int:

@@ -21,7 +21,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.api import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -86,6 +85,6 @@ def pipeline_apply(block_fn: Callable, stacked_params, x, *, mesh,
     pspecs = jax.tree.map(lambda a: P(axis, *([None] * (a.ndim - 1))),
                           stacked_params)
     del pp
-    return shard_map(
+    return jax.shard_map(
         stage_fn, mesh=mesh, in_specs=(pspecs, P()), out_specs=P(),
         check_vma=False)(stacked_params, x)

@@ -20,12 +20,41 @@ stay per-instance, and their sites carry allowlist reasons instead.
 Keys are plain hashable tuples, conventionally ``("<site>", cfg, ...)``
 with every closure-captured constant included — a key that under-describes
 its closure silently serves the wrong function.
+
+:func:`enable_compile_cache` is the other half: JAX's PERSISTENT
+compilation cache, so separate processes (a server restart, the next
+benchmark run) reuse compiled programs from disk. Entry points call it;
+importing this module never does.
 """
 from __future__ import annotations
 
+import os
 from typing import Callable
 
 SHARED_JITS: dict = {}
+
+# the fixed in-checkout default: the directory is part of the cache key, so
+# a path that moved between runs (a temp name, a pid) would never hit
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", ".jax_cache")
+
+
+def compile_cache_dir() -> str:
+    """``$JAX_COMPILATION_CACHE_DIR`` when set, else ``<repo>/.jax_cache``."""
+    return os.path.normpath(os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                            or DEFAULT_COMPILE_CACHE_DIR)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+    When ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, so
+    nothing is configured here; otherwise the cache goes to the fixed
+    in-checkout default."""
+    path = compile_cache_dir()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def shared_jit(key, build: Callable):

@@ -1,6 +1,6 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On this container (CPU) kernels run with interpret=True; on a real TPU
+On the CPU kernels run with interpret=True; on a real TPU
 backend the same call sites compile to Mosaic. ``use_pallas()`` central-
 switches; model code goes through these wrappers only where the kernel is
 profitable (full-seq attention, the NBL block GEMM, covariance updates).

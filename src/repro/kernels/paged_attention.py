@@ -1,4 +1,4 @@
-"""Paged-attention decode Pallas kernel (TPU target, interpret-validated).
+"""Paged-attention decode Pallas kernel (TPU target).
 
 Single-token decode against the page-pool KV layout of models/paging.py:
 each serving slot's K/V live in fixed-size, position-aligned pages scattered
@@ -20,13 +20,16 @@ check (unallocated table entries are clamped to page 0 by the index_map and
 killed by the mask). GQA (q heads grouped per kv head), sliding window and
 Gemma-2 logit soft-capping match kernels/flash_attention.py semantics.
 
-MXU alignment for real TPUs wants page_size a multiple of the sublane tile
-and head_dim padded to 128 lanes (ops.attention-style); interpret mode (this
-container) accepts the tiny test shapes as-is.
+MXU alignment for real TPUs wants head_dim padded to 128 lanes
+(ops.attention-style); interpret mode accepts the tiny test shapes as-is.
+For bf16 pools, page sizes 64 and 8 (below the 16-row sublane tile) both
+compile for v5e (tests/test_chip_compile.py).
 
 ``paged_decode`` is the call-site dispatcher: the Pallas kernel on TPU, the
 pure-XLA gather reference (``paged_decode_xla``) elsewhere — the kernel is
-validated against the reference in interpret mode by tests/test_paging.py.
+checked against the reference in interpret mode by tests/test_paging.py and
+compiled on a TPU v5e by chip_smoke.py. Under a mesh the kernel runs in a
+shard_map over KV heads (``_sharded_kernel``).
 """
 from __future__ import annotations
 
@@ -37,6 +40,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from repro.distributed.api import axis_size, mesh_axes
 
 NEG_INF = -2.0e38
 
@@ -235,6 +241,25 @@ def paged_mixed_xla(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     return out.astype(q.dtype)
 
 
+def _sharded_kernel(q, k_pages, v_pages, page_tbl, lengths, **kw):
+    """``paged_attention`` under whatever mesh is ambient. A pallas_call
+    has no GSPMD partitioning rule, so under a mesh it runs in a
+    shard_map: q and the pools split over KV heads on "model" (each
+    shard's heads read only their own slice of every page) and the page
+    table and lengths are replicated. KV heads that do not divide the
+    "model" axis run replicated."""
+    fn = functools.partial(paged_attention, **kw)
+    axes = mesh_axes()
+    if not axes:
+        return fn(q, k_pages, v_pages, page_tbl, lengths)
+    kv = "model" if ("model" in axes
+                     and q.shape[1] % axis_size("model") == 0) else None
+    heads = P(None, kv, None, None)    # q (B, KV, rep, hd); pools likewise
+    return jax.shard_map(fn, in_specs=(heads, heads, heads, P(), P()),
+                         out_specs=heads, check_vma=False)(
+        q, k_pages, v_pages, page_tbl, lengths)
+
+
 def paged_mixed(q, k_pages, v_pages, page_tbl, row_pos, row_len, *,
                 scale: Optional[float] = None, window: Optional[int] = None,
                 softcap: Optional[float] = None,
@@ -257,7 +282,7 @@ def paged_mixed(q, k_pages, v_pages, page_tbl, row_pos, row_len, *,
     valid = jnp.arange(w, dtype=jnp.int32)[None, :] \
         < row_len.astype(jnp.int32)[:, None]
     lens = jnp.where(valid, tpos + 1, 0).reshape(-1)
-    out = paged_attention(qv, k_pages, v_pages,
+    out = _sharded_kernel(qv, k_pages, v_pages,
                           jnp.repeat(page_tbl, w, axis=0), lens,
                           scale=scale, window=window, softcap=softcap)
     return out.reshape(b, w, kvh, rep, hd).transpose(0, 2, 3, 1, 4)
@@ -273,7 +298,7 @@ def paged_decode(q, k_pages, v_pages, page_tbl, lengths, *,
     if use_kernel is None:
         use_kernel = jax.default_backend() == "tpu"
     if use_kernel:
-        return paged_attention(q, k_pages, v_pages, page_tbl, lengths,
+        return _sharded_kernel(q, k_pages, v_pages, page_tbl, lengths,
                                scale=scale, window=window, softcap=softcap)
     return paged_decode_xla(q, k_pages, v_pages, page_tbl, lengths,
                             scale=scale, window=window, softcap=softcap)

@@ -41,7 +41,6 @@ def main() -> None:
 
     import jax
     from repro.configs import get_config
-    from repro.distributed.api import use_mesh
     from repro.data import ShardedLoader
     from repro.launch.mesh import make_production_mesh
     from repro.launch.train import train
@@ -64,7 +63,7 @@ def main() -> None:
     if pid == 0:
         print(f"[bootstrap] {args.arch} on {mesh.shape} "
               f"({len(jax.devices())} devices, {nproc} hosts)")
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         train(cfg, steps=args.steps, global_batch=args.global_batch,
               seq=args.seq, peak_lr=args.lr, schedule_name=args.schedule,
               ckpt_dir=args.ckpt, loader=loader,

@@ -31,9 +31,7 @@ from repro.configs import (  # noqa: E402
     ASSIGNED_ARCHS, SHAPES, get_config, shape_applicable,
 )
 from repro.core.surgery import nbl_variant  # noqa: E402
-from repro.distributed.api import (  # noqa: E402
-    jit_shardings, shaped_spec, use_mesh,
-)
+from repro.distributed.api import shaped_spec  # noqa: E402
 from repro.distributed.sharding import (  # noqa: E402
     batch_specs, cache_specs, param_specs,
 )
@@ -156,7 +154,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, nbl_m: int = 0,
     chips = int(np.prod(tuple(mesh.shape.values())))
     t0 = time.time()
     try:
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             fn, args, shardings, ntok, backward = build_target(cfg, shape)
             donate_args = ()
             if donate and shape.kind == "train":
@@ -164,7 +162,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, nbl_m: int = 0,
             elif donate and shape.kind in ("decode", "serve", "serve_paged",
                                            "fused_step"):
                 donate_args = (2,)
-            lowered = jax.jit(fn, in_shardings=jit_shardings(shardings),  # nbl: disable=jit-discipline -- AOT lower/compile cell: the jit exists to be lowered once and measured, never reused
+            lowered = jax.jit(fn, in_shardings=shardings,  # nbl: disable=jit-discipline -- AOT lower/compile cell: the jit exists to be lowered once and measured, never reused
                               donate_argnums=donate_args).lower(*args)
             compiled = lowered.compile()
             try:

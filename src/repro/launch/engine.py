@@ -227,7 +227,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
-from repro.distributed.api import jit_shardings, mesh_axes, shaped_spec
+from repro.distributed.api import mesh_axes, shaped_spec
 from repro.distributed.sharding import cache_specs, param_specs
 from repro.jitcache import SHARED_JITS as _SHARED_JITS, shared_jit as _shared_jit
 from repro.launch.scheduler import (
@@ -300,7 +300,7 @@ class Engine:
     ``max_prefill_tokens_per_step`` as the single pacing knob.
 
     Sharding is captured at CONSTRUCTION time: build the engine inside
-    ``use_mesh(mesh)`` to get sharded params/caches — an engine built
+    ``jax.set_mesh(mesh)`` to get sharded params/caches — an engine built
     un-meshed stays fully replicated even if later driven under a mesh.
 
     ``obs`` (an ``repro.obs.Observability``, default None = off) threads
@@ -541,6 +541,12 @@ class Engine:
             if sharded else None
         cspecs = cache_specs(jax.eval_shape(lambda: self.cache)) \
             if sharded else None
+        if sharded:
+            # the jits below pin their inputs to these layouts, and jit
+            # refuses a committed array laid out otherwise (the cache was
+            # just built replicated on the mesh): place both once here
+            self.params = jax.device_put(params, pspecs)
+            self.cache = jax.device_put(self.cache, cspecs)
 
         dkw = dict(donate_argnums=(2,)) if donate else {}
         akw = dict(donate_argnums=(0,)) if donate else {}
@@ -561,11 +567,11 @@ class Engine:
             if self.paged:
                 din += (shaped_spec((self.n_slots, self._pps), "dp", None),)
             self._decode_jit = jax.jit(  # nbl: disable=jit-discipline -- sharded, per-instance by design
-                _decode, in_shardings=jit_shardings(din),
-                out_shardings=jit_shardings((None, cspecs)), **dkw)
+                _decode, in_shardings=din,
+                out_shardings=(None, cspecs), **dkw)
             self._assign_jit = jax.jit(  # nbl: disable=jit-discipline -- sharded, per-instance by design
-                _assign, in_shardings=jit_shardings((cspecs, None, None)),
-                out_shardings=jit_shardings(cspecs), **akw)
+                _assign, in_shardings=(cspecs, None, None),
+                out_shardings=cspecs, **akw)
         else:
             self._decode_jit = _shared_jit(
                 ("decode", cfg, self.paged, donate),
@@ -579,6 +585,10 @@ class Engine:
         # reshards on admission.
         self._pspecs = pspecs
         self._sharded = sharded
+        # the mesh every step runs under: jits resolve their specs, and the
+        # paged kernel its shard_map, against the AMBIENT mesh, which is
+        # per-thread — AsyncEngine steps on a thread of its own
+        self._mesh = jax.sharding.get_mesh() if sharded else None
         if self.drafts and sharded:
             # the draft-burst view gathers raw pool leaves at trace time;
             # it has no sharding specs, so spec stays unsharded-only
@@ -751,8 +761,8 @@ class Engine:
                 if prefix_pages:
                     ins += (self._cspecs, None, None)
                 ins += (None,) if with_enc else ()
-                kw = dict(in_shardings=jit_shardings(ins),
-                          out_shardings=jit_shardings((None, pcspecs)))
+                kw = dict(in_shardings=ins,
+                          out_shardings=(None, pcspecs))
                 fn = jax.jit(_prefill, **kw)  # nbl: disable=jit-discipline -- sharded, per-instance by design
             else:
                 fn = _shared_jit(("prefill", cfg, paged) + key,
@@ -773,9 +783,9 @@ class Engine:
             if self._sharded:
                 from repro.launch.specs import cache_shapes
                 pcspecs = cache_specs(cache_shapes(cfg, 1, cache_len))
-                kw.update(in_shardings=jit_shardings(
-                    (self._cspecs, pcspecs, None, None)),
-                    out_shardings=jit_shardings(self._cspecs))
+                kw.update(in_shardings=
+                    (self._cspecs, pcspecs, None, None),
+                    out_shardings=self._cspecs)
                 fn = jax.jit(_assign, **kw)  # nbl: disable=jit-discipline -- sharded, per-instance by design
             else:
                 fn = _shared_jit(("assign_paged", cfg, ps, bool(kw)),
@@ -807,8 +817,8 @@ class Engine:
                        vec_spec,
                        shaped_spec((self.n_slots, self._pps), "dp", None))
                 fn = jax.jit(  # nbl: disable=jit-discipline -- sharded, per-instance by design
-                    _fused, in_shardings=jit_shardings(din),
-                    out_shardings=jit_shardings((None, self._cspecs)),
+                    _fused, in_shardings=din,
+                    out_shardings=(None, self._cspecs),
                     **dkw)
             else:
                 fn = _shared_jit(("fused_step", cfg, width, self._donate),
@@ -1427,7 +1437,16 @@ class Engine:
         With obs attached, the step is timed (host wall + the decode
         dispatch/readback split) and rolled up into one StepRecord +
         engine-track trace span; all of that is host-side bookkeeping —
-        the device sees the exact same dispatch sequence either way."""
+        the device sees the exact same dispatch sequence either way.
+
+        A sharded engine steps under the mesh it was built in, whichever
+        thread calls."""
+        if self._mesh is not None:
+            with jax.set_mesh(self._mesh):
+                return self._step_observed()
+        return self._step_observed()
+
+    def _step_observed(self) -> int:
         if self.obs is None:
             return self._step_impl(None)
         t0 = time.monotonic()

@@ -3,16 +3,10 @@ never touches jax device state (device count is locked at first use)."""
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types on the mesh
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto-typed
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.distributed.api import jit_shardings
 from repro.distributed.sharding import cache_specs, param_specs
 from repro.jitcache import shared_jit
 from repro.launch.engine import Engine
@@ -76,12 +75,12 @@ def make_serve_fns(cfg: ModelConfig, *, batch: int, prompt_len: int,
     enc_spec = (P("data", None, None),) if cfg.family == "vlm" else ()
     prefill_jit = jax.jit(  # nbl: disable=jit-discipline -- sharded: shardings captured from the caller's mesh, per-mesh by design
         _prefill,
-        in_shardings=jit_shardings((pspecs, P("data", None)) + enc_spec),
-        out_shardings=jit_shardings((None, cspecs)))
+        in_shardings=(pspecs, P("data", None)) + enc_spec,
+        out_shardings=(None, cspecs))
     decode_jit = jax.jit(  # nbl: disable=jit-discipline -- sharded: shardings captured from the caller's mesh, per-mesh by design
         _decode,
-        in_shardings=jit_shardings((pspecs, P("data", None), cspecs, P())),
-        out_shardings=jit_shardings((None, cspecs)),
+        in_shardings=(pspecs, P("data", None), cspecs, P()),
+        out_shardings=(None, cspecs),
         donate_argnums=(2,) if donate else ())
     return prefill_jit, decode_jit
 

@@ -362,6 +362,8 @@ def main(argv: Optional[list] = None) -> int:
                          "enabled; open at https://ui.perfetto.dev)")
     args = ap.parse_args(argv)
 
+    from repro.jitcache import enable_compile_cache
+    enable_compile_cache()
     eng = _build_engine(args)
     step_cb = None
     if args.step_delay_s > 0:

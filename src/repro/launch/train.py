@@ -18,7 +18,7 @@ from jax.sharding import PartitionSpec as P
 from repro.checkpoint import CheckpointManager
 from repro.configs.base import ModelConfig
 from repro.data import ShardedLoader
-from repro.distributed.api import jit_shardings, mesh_axes
+from repro.distributed.api import mesh_axes
 from repro.distributed.sharding import batch_specs, param_specs, zero1_specs
 from repro.launch.specs import input_specs, param_shapes
 from repro.models import init_params, loss_fn
@@ -28,7 +28,7 @@ from repro.optim import adamw_init, adamw_update, get_schedule
 def make_train_step(cfg: ModelConfig, *, schedule: Callable,
                     zero1: bool = True, remat: bool = True,
                     weight_decay: float = 0.1, donate: bool = True):
-    """jit'd sharded train step. Call under `use_mesh(mesh)`."""
+    """jit'd sharded train step. Call under `jax.set_mesh(mesh)`."""
     def step_fn(params, opt, batch, step):
         (loss, metrics), grads = jax.value_and_grad(
             lambda p: loss_fn(cfg, p, batch, remat=remat),
@@ -55,8 +55,8 @@ def make_train_step(cfg: ModelConfig, *, schedule: Callable,
         bspecs = batch_specs(batch_shapes)
         return jax.jit(  # nbl: disable=jit-discipline -- sharded: shardings captured from the ambient mesh, per-run by design
             step_fn,
-            in_shardings=jit_shardings((pspecs, ospecs, bspecs, P())),
-            out_shardings=jit_shardings((pspecs, ospecs, None)),
+            in_shardings=(pspecs, ospecs, bspecs, P()),
+            out_shardings=(pspecs, ospecs, None),
             donate_argnums=(0, 1) if donate else ())
     return step_fn, shardings_for, pspecs, ospecs
 

@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed import constrain
-from repro.distributed.api import shard_map
 from repro.models.layers import init_mlp, mlp
 
 
@@ -195,7 +194,7 @@ def _moe_ffn_ep(cfg, p: dict, x: jax.Array) -> tuple[jax.Array, jax.Array]:
     pspecs = {"router": P(), "w_gate": w_in, "w_up": w_in,
               "w_down": w_down_in}
     x_in = P(dps, None, None) if dp else P(None, None, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         shard_fn, in_specs=(pspecs, x_in), out_specs=(x_in, P()),
         axis_names=set(dp) | {"model"}, check_vma=False)(px, x)
     if m.n_shared:

@@ -38,11 +38,9 @@ def dequantize_int8(q: jax.Array, scale: jax.Array) -> jax.Array:
 def compressed_psum(grads, err, axes):
     """Error-feedback int8 all-reduce of a gradient pytree over mesh
     ``axes``. Returns (mean_grads_f32, new_err). Call inside shard_map."""
-    axis_size = getattr(jax.lax, "axis_size",
-                        lambda a: jax.lax.psum(1, a))  # pre-0.5 fallback
     nshards = 1
     for a in (axes if isinstance(axes, (tuple, list)) else (axes,)):
-        nshards *= axis_size(a)
+        nshards *= jax.lax.axis_size(a)
 
     def one(g, e):
         g32 = g.astype(jnp.float32) + e

@@ -140,9 +140,6 @@ def summarize(hlo_text: str, chips: int, cfg=None,
     out = {"hlo_flops": flops, "hlo_bytes": bts,
            "collectives": coll, **terms, "chips": chips}
     if xla_cost:
-        # old jax returns cost_analysis() as a one-element list of dicts
-        if isinstance(xla_cost, (list, tuple)):
-            xla_cost = xla_cost[0] if xla_cost else {}
         out["xla_cost_flops"] = float(xla_cost.get("flops", 0.0))
     if cfg is not None and n_tokens:
         mf = model_flops(cfg, n_tokens, backward=backward)

@@ -11,7 +11,6 @@ import warnings; warnings.filterwarnings('ignore')
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
 from repro.launch.mesh import make_mesh
-from repro.distributed.api import use_mesh
 from repro.launch.train import make_train_step, init_state
 from repro.data import ShardedLoader
 from repro.optim import get_schedule
@@ -31,7 +30,7 @@ for i in range(3):
 
 # 8-device (2 data x 4 model) mesh
 mesh = make_mesh((2, 4), ('data', 'model'))
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     _, sf, _, _ = make_train_step(cfg, schedule=sched, zero1=True)
     params, opt = init_state(cfg, 0)
     step = sf(jax.eval_shape(lambda: jax.tree.map(jnp.asarray, loader.batch(0))))
@@ -78,7 +77,6 @@ import warnings; warnings.filterwarnings('ignore')
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_mesh
-from repro.distributed.api import shard_map
 from repro.optim import compressed_psum, ef_init
 
 mesh = make_mesh((4,), ('data',))
@@ -89,7 +87,7 @@ def fn(g_local, err):
     mean, new_err = compressed_psum({'g': g_local}, {'g': err}, ('data',))
     return mean['g'], new_err['g']
 
-sharded = shard_map(fn, mesh=mesh, in_specs=(P('data'), P('data')),
+sharded = jax.shard_map(fn, mesh=mesh, in_specs=(P('data'), P('data')),
                         out_specs=(P(), P('data')), check_vma=False)
 got, err = sharded(g.reshape(4, 64), jnp.zeros((4, 64)))
 want = g.mean(0)
@@ -108,7 +106,6 @@ import warnings; warnings.filterwarnings('ignore')
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_mesh
-from repro.distributed.api import shard_map
 from repro.optim import compressed_psum
 
 mesh = make_mesh((4,), ('data',))
@@ -130,7 +127,7 @@ def train(compressed):
                 m, ne = compressed_psum({'g': g}, {'g': errl}, ('data',))
                 return m['g'], ne['g']
             return jax.lax.pmean(g, 'data'), errl
-        sm = shard_map(step, mesh=mesh,
+        sm = jax.shard_map(step, mesh=mesh,
                            in_specs=(P('data'), P('data'), P('data')),
                            out_specs=(P(), P('data')), check_vma=False)
         g, err = sm(X, y, err)
@@ -152,7 +149,6 @@ import warnings; warnings.filterwarnings('ignore')
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.launch.mesh import make_mesh
-from repro.distributed.api import use_mesh
 from repro.core.moments import init_moments, update_moments, finalize
 
 mesh = make_mesh((4,), ('data',))
@@ -160,7 +156,7 @@ key = jax.random.PRNGKey(0)
 x = jax.random.normal(key, (64, 16))
 y = jax.random.normal(jax.random.PRNGKey(1), (64, 16))
 host = finalize(update_moments(init_moments(16, 16), x, y))
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     xs = jax.device_put(x, NamedSharding(mesh, P('data')))
     ys = jax.device_put(y, NamedSharding(mesh, P('data')))
     mom = jax.jit(lambda a, b: update_moments(init_moments(16, 16), a, b))(xs, ys)
@@ -179,7 +175,6 @@ import tempfile
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding
 from repro.launch.mesh import make_mesh
-from repro.distributed.api import use_mesh
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.distributed.sharding import param_specs, named
@@ -189,13 +184,13 @@ cfg = get_config('tiny-dense')
 params = init_params(jax.random.PRNGKey(0), cfg)
 with tempfile.TemporaryDirectory() as d:
     m1 = make_mesh((2, 4), ('data', 'model'))
-    with use_mesh(m1):
+    with jax.set_mesh(m1):
         sh = named(param_specs(params), m1)
         p1 = jax.tree.map(jax.device_put, params, sh)
         mgr = CheckpointManager(d)
         mgr.save(1, p1)
     m2 = make_mesh((4, 2), ('data', 'model'))
-    with use_mesh(m2):
+    with jax.set_mesh(m2):
         sh2 = named(param_specs(params), m2)
         flatsh = {}
         paths = jax.tree_util.tree_flatten_with_path(sh2)[0]

@@ -234,7 +234,6 @@ def test_engine_sharded_parity(subproc):
 import warnings; warnings.filterwarnings('ignore')
 import jax, numpy as np, jax.numpy as jnp
 from repro.configs import get_config
-from repro.distributed.api import use_mesh
 from repro.launch.mesh import make_mesh
 from repro.launch.engine import Engine
 from repro.launch.serve import generate
@@ -247,12 +246,52 @@ prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
            for n in (6, 9, 7)]
 refs = [np.asarray(generate(cfg, params, jnp.asarray(p)[None],
                             max_new=4))[0] for p in prompts]
-with use_mesh(make_mesh((2, 2), ('data', 'model'))):
+with jax.set_mesh(make_mesh((2, 2), ('data', 'model'))):
     eng = Engine(cfg, params, max_len=16, n_slots=2)
     rids = [eng.submit(p, 4) for p in prompts]
     out = eng.run()
 for i, r in enumerate(rids):
     np.testing.assert_array_equal(out[r], refs[i])
+print('OK')
+""", n_devices=4)
+
+
+def test_paged_engine_sharded_async_parity(subproc):
+    """The multi-chip serving path: a PAGED engine built under a (data,
+    model) mesh, stepped by AsyncEngine's own thread (the mesh is
+    per-thread, so the engine must carry it), with attention routed
+    through the Pallas kernel (interpret mode here) — which a mesh can
+    only partition inside a shard_map over KV heads. Tokens match the
+    unmeshed generate() reference."""
+    subproc("""
+import functools, warnings; warnings.filterwarnings('ignore')
+import jax, numpy as np, jax.numpy as jnp
+from repro.configs import get_config
+from repro.kernels import paged_attention as pa
+from repro.launch.mesh import make_mesh
+from repro.launch.engine import AsyncEngine, Engine
+from repro.launch.serve import generate
+from repro.models import init_params
+
+pa.paged_attention = functools.partial(pa.paged_attention, interpret=True)
+pa.paged_decode = functools.partial(pa.paged_decode, use_kernel=True)
+pa.paged_mixed = functools.partial(pa.paged_mixed, use_kernel=True)
+cfg = get_config('tiny-dense')
+params = init_params(jax.random.PRNGKey(0), cfg)
+rng = np.random.default_rng(0)
+prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+           for n in (6, 9, 7)]
+with jax.set_mesh(make_mesh((2, 2), ('data', 'model'))):
+    eng = Engine(cfg, params, max_len=16, n_slots=2, paged=True,
+                 page_size=4)
+with AsyncEngine(eng) as aeng:
+    streams = [aeng.submit_stream(p, 4) for p in prompts]
+    outs = [s.result(timeout=300) for s in streams]
+refs = [np.asarray(generate(cfg, params, jnp.asarray(p)[None],
+                            max_new=4))[0] for p in prompts]
+for got, want in zip(outs, refs):
+    np.testing.assert_array_equal(got, want)
+assert eng.allocator.in_use == 0
 print('OK')
 """, n_devices=4)
 
