@@ -1437,7 +1437,11 @@ class Engine:
         With obs attached, the step is timed (host wall + the decode
         dispatch/readback split) and rolled up into one StepRecord +
         engine-track trace span; all of that is host-side bookkeeping —
-        the device sees the exact same dispatch sequence either way.
+        the device sees the exact same dispatch sequence either way. Its
+        phases are annotated for the profiler: ``nbl.step`` around the
+        whole step, with ``nbl.admission``, then on the fused path
+        ``nbl.plan``, ``nbl.fused_step``, ``nbl.readback`` and
+        ``nbl.commit`` inside it.
 
         A sharded engine steps under the mesh it was built in, whichever
         thread calls."""
@@ -1453,7 +1457,8 @@ class Engine:
         st = {"dispatch_s": 0.0, "n_decoding": 0, "n_chunking": 0,
               "chunk_tokens": 0, "prefill_tokens0": self.n_prefill_tokens,
               "tokens_planned": 0, "budget_utilization": 0.0}
-        emitted = self._step_impl(st)
+        with self.obs.annotate("nbl.step"):
+            emitted = self._step_impl(st)
         self.obs.on_step(
             self, t0=t0, t1=time.monotonic(), dispatch_s=st["dispatch_s"],
             n_decoding=st["n_decoding"], n_chunking=st["n_chunking"],
@@ -1483,42 +1488,45 @@ class Engine:
         decoding slot 1 token — decode priority extends to admission —
         while the queue HEAD is always admitted (Scheduler.admit), so an
         over-budget prompt cannot livelock."""
-        free = [i for i, r in enumerate(self.slot_req) if r is None]
-        emitted = 0
-        budget = None
-        if self.fused and self.step_tokens is not None:
-            n_dec = sum(1 for s in self.active_slots
-                        if self.slot_chunk_pos[s] < 0)
-            budget = decode_first_budget(self.step_tokens, n_dec)
-        pending = self.scheduler.admit(len(free), budget=budget)
-        while pending:
-            req = pending.pop(0)
-            if len(req.prompt) + req.max_new > self.max_len:
-                # length guard at ADMISSION: requests submitted directly to
-                # the scheduler bypass Engine.submit's check and would
-                # otherwise index past the page table mid-decode
-                self._reject(req, f"prompt({len(req.prompt)}) + max_new"
-                             f"({req.max_new}) exceeds max_len"
-                             f"={self.max_len}")
-                continue
-            serr = self._spec_guard(len(req.prompt), req.max_new,
-                                    req.spec_gamma, req.draft_m)
-            if serr is not None:
-                # same guard submit() runs: direct scheduler submissions
-                # must not reach the spec path unservable
-                self._reject(req, serr)
-                continue
-            n_shared, shared_ids = self._prefix_lookup(req)
-            if not self._can_admit(req, n_shared):
-                if n_shared:
-                    self.allocator.unref(shared_ids)   # drop the pin
-                for r in reversed([req] + pending):   # restore FIFO order
-                    self.scheduler.requeue(r)
-                break
-            self._admit(req, free.pop(), n_shared, shared_ids)
-            if not self.chunked:
-                emitted += 1                   # prefill emits a first token
-        return emitted
+        with (self.obs.annotate("nbl.admission")
+              if self.obs is not None else _NULLCTX):
+            free = [i for i, r in enumerate(self.slot_req) if r is None]
+            emitted = 0
+            budget = None
+            if self.fused and self.step_tokens is not None:
+                n_dec = sum(1 for s in self.active_slots
+                            if self.slot_chunk_pos[s] < 0)
+                budget = decode_first_budget(self.step_tokens, n_dec)
+            pending = self.scheduler.admit(len(free), budget=budget)
+            while pending:
+                req = pending.pop(0)
+                if len(req.prompt) + req.max_new > self.max_len:
+                    # length guard at ADMISSION: requests submitted
+                    # directly to the scheduler bypass Engine.submit's
+                    # check and would otherwise index past the page table
+                    # mid-decode
+                    self._reject(req, f"prompt({len(req.prompt)}) + max_new"
+                                 f"({req.max_new}) exceeds max_len"
+                                 f"={self.max_len}")
+                    continue
+                serr = self._spec_guard(len(req.prompt), req.max_new,
+                                        req.spec_gamma, req.draft_m)
+                if serr is not None:
+                    # same guard submit() runs: direct scheduler submissions
+                    # must not reach the spec path unservable
+                    self._reject(req, serr)
+                    continue
+                n_shared, shared_ids = self._prefix_lookup(req)
+                if not self._can_admit(req, n_shared):
+                    if n_shared:
+                        self.allocator.unref(shared_ids)   # drop the pin
+                    for r in reversed([req] + pending):   # restore FIFO order
+                        self.scheduler.requeue(r)
+                    break
+                self._admit(req, free.pop(), n_shared, shared_ids)
+                if not self.chunked:
+                    emitted += 1           # prefill emits a first token
+            return emitted
 
     def _spec_rounds(self, active: list[int]) -> tuple[int, list[int]]:
         """Shared by both paths: one draft+verify round per live spec slot
@@ -1605,12 +1613,14 @@ class Engine:
         rounds, then EXECUTE one fused dispatch and COMMIT."""
         emitted = 0
         plan = StepPlan(budget=self.step_tokens)
-        row_req = self._plan_chunks(plan)
-        self._ensure_decode_pages()          # fused implies paged
-        active = self.active_slots
-        if self.chunked:
-            active = [s for s in active if self.slot_chunk_pos[s] < 0]
-        se, active = self._spec_rounds(active)
+        with (self.obs.annotate("nbl.plan")
+              if self.obs is not None else _NULLCTX):
+            row_req = self._plan_chunks(plan)
+            self._ensure_decode_pages()          # fused implies paged
+            active = self.active_slots
+            if self.chunked:
+                active = [s for s in active if self.slot_chunk_pos[s] < 0]
+            se, active = self._spec_rounds(active)
         emitted += se
         # paging faults / spec rounds above may have preempted slots the
         # plan selected: keep decode rows from the survivors and chunk
@@ -1677,55 +1687,61 @@ class Engine:
         transition — chunk progress (+ progressive prefix publication),
         final-chunk seed emission, decode emission, retirement — all
         through the same _emit the legacy path uses."""
-        # host-sync: readback -- THE per-step readback: every row's last-
-        # valid-token logits row comes host-side once; decode sampling
-        # AND final-chunk seed tokens are both served from this one fetch
-        rows = np.asarray(logits[:, -1], np.float32)
+        with (self.obs.annotate("nbl.readback")
+              if self.obs is not None else _NULLCTX):
+            # host-sync: readback -- THE per-step readback: every row's
+            # last-valid-token logits row comes host-side once; decode
+            # sampling AND final-chunk seed tokens are both served from
+            # this one fetch
+            rows = np.asarray(logits[:, -1], np.float32)
         if st is not None:
             # dispatch + the logits device->host readback the sample needs
             st["dispatch_s"] = time.monotonic() - td0
-        emitted = 0
-        now = time.monotonic()
-        ps = self.page_size
-        ctoks = 0
-        for c in plan.chunk_rows:
-            req = self.slot_req[c.slot]
-            self.n_chunks += 1
-            # same per-chunk accounting as the legacy _run_partial_prefill
-            # path, so counters stay path-independent per chunk
-            self.n_prefills += 1
-            self.n_prefill_tokens += c.length
-            ctoks += c.length
-            if self.obs is not None:
-                self.obs.on_prefill(c.length)
-                self.obs.on_chunk(req, td0, now, c.start, c.end, c.final)
-            if self.prefix_sharing and c.end // ps:
-                # publish full pages PROGRESSIVELY (see
-                # _run_partial_prefill): later admissions can share a long
-                # prompt's head while its tail still chunks
-                self.prefix_index.insert(
-                    req.prompt[:c.end],
-                    self.page_tbl[c.slot, :c.end // ps], self.allocator)
-            if c.final:
-                # chunking -> decoding: the row's last-token logits seed
-                # the request's first generated token
-                self.slot_chunk_pos[c.slot] = -1
-                self.slot_pos[c.slot] = len(req.prompt)
-                self._emit(req, c.slot, self._sample(rows[c.slot]), now)
+        with (self.obs.annotate("nbl.commit")
+              if self.obs is not None else _NULLCTX):
+            emitted = 0
+            now = time.monotonic()
+            ps = self.page_size
+            ctoks = 0
+            for c in plan.chunk_rows:
+                req = self.slot_req[c.slot]
+                self.n_chunks += 1
+                # same per-chunk accounting as the legacy
+                # _run_partial_prefill path, so counters stay
+                # path-independent per chunk
+                self.n_prefills += 1
+                self.n_prefill_tokens += c.length
+                ctoks += c.length
+                if self.obs is not None:
+                    self.obs.on_prefill(c.length)
+                    self.obs.on_chunk(req, td0, now, c.start, c.end, c.final)
+                if self.prefix_sharing and c.end // ps:
+                    # publish full pages PROGRESSIVELY (see
+                    # _run_partial_prefill): later admissions can share a
+                    # long prompt's head while its tail still chunks
+                    self.prefix_index.insert(
+                        req.prompt[:c.end],
+                        self.page_tbl[c.slot, :c.end // ps], self.allocator)
+                if c.final:
+                    # chunking -> decoding: the row's last-token logits seed
+                    # the request's first generated token
+                    self.slot_chunk_pos[c.slot] = -1
+                    self.slot_pos[c.slot] = len(req.prompt)
+                    self._emit(req, c.slot, self._sample(rows[c.slot]), now)
+                    emitted += 1
+                else:
+                    self.slot_chunk_pos[c.slot] = c.end
+            if st is not None:
+                st["chunk_tokens"] = ctoks
+            if plan.decode_slots and np.any(self.slot_chunk_pos >= 0):
+                self.n_interleaved_decode_steps += 1  # decode BETWEEN chunks
+            for slot in plan.decode_slots:
+                req = self.slot_req[slot]
+                assert req is not None         # snapshot taken post-preempt
+                self.slot_pos[slot] += 1
+                self._emit(req, slot, self._sample(rows[slot]), now)
                 emitted += 1
-            else:
-                self.slot_chunk_pos[c.slot] = c.end
-        if st is not None:
-            st["chunk_tokens"] = ctoks
-        if plan.decode_slots and np.any(self.slot_chunk_pos >= 0):
-            self.n_interleaved_decode_steps += 1   # decode BETWEEN chunks
-        for slot in plan.decode_slots:
-            req = self.slot_req[slot]
-            assert req is not None             # snapshot taken post-preempt
-            self.slot_pos[slot] += 1
-            self._emit(req, slot, self._sample(rows[slot]), now)
-            emitted += 1
-        return emitted
+            return emitted
 
     # ----------------------------------------------------- legacy stepping --
 

@@ -209,8 +209,10 @@ def _block_fwd(cfg: ModelConfig, blk: Block, p, x, *, mode: str,
         x = x + h.astype(x.dtype)
     elif blk.kind in ("nbl", "nbl_block"):
         # the paper's replacement: one GEMM, residual retained (Alg. 2).
-        h = x @ p["mixer"]["w"].astype(x.dtype) + p["mixer"]["b"].astype(x.dtype)
-        x = x + h
+        with jax.named_scope("nbl"):
+            h = (x @ p["mixer"]["w"].astype(x.dtype)
+                 + p["mixer"]["b"].astype(x.dtype))
+            x = x + h
     elif blk.kind in ("drop", "drop_block"):
         pass
 
@@ -219,8 +221,9 @@ def _block_fwd(cfg: ModelConfig, blk: Block, p, x, *, mode: str,
 
     # ---- ffn --------------------------------------------------------------
     if blk.ffn == "dense":
-        h = rmsnorm(x, p["norm2"], cfg.norm_eps)
-        x = x + mlp(p["ffn"], h, cfg.mlp_act).astype(x.dtype)
+        with jax.named_scope("mlp"):
+            h = rmsnorm(x, p["norm2"], cfg.norm_eps)
+            x = x + mlp(p["ffn"], h, cfg.mlp_act).astype(x.dtype)
     elif blk.ffn == "moe":
         h = rmsnorm(x, p["norm2"], cfg.norm_eps)
         y, aux = moe_ffn(cfg, p["ffn"], h)

@@ -48,11 +48,16 @@ clock = time.perf_counter       # the one sanctioned high-res timer
 class Observability:
     """The hook layer the engine drives; owns registry + tracer + timeline.
 
-    Default level records everything except jax profiler annotations
-    (``trace_annotations=True`` wraps the prefill/decode jit calls in
-    ``jax.profiler.TraceAnnotation`` so device profiles line up with the
-    host timeline). ``trace=False`` / ``timeline_capacity=0`` shed the
-    corresponding subsystem; the registry always exists.
+    Default level records everything except jax profiler annotations.
+    ``trace_annotations=True`` makes every ``annotate`` site a
+    ``jax.profiler.TraceAnnotation``: the jit calls (``nbl.prefill``,
+    ``nbl.decode``, ``nbl.fused_step``, ``nbl.spec_*``) and the engine
+    step's phases (``nbl.step`` enclosing ``nbl.admission``,
+    ``nbl.plan``, ``nbl.readback``, ``nbl.commit``). The spans land in the
+    profiler's own timeline, on the device trace's clock, so an idle gap
+    on the device can be put down to a host phase. ``trace=False`` /
+    ``timeline_capacity=0`` shed the corresponding subsystem; the
+    registry always exists.
     """
 
     def __init__(self, *, labels: Optional[dict] = None, trace: bool = True,
@@ -160,9 +165,9 @@ class Observability:
         self.registry.bind(**labels)
 
     def annotate(self, name: str):
-        """Context manager around a jit call site: a jax profiler
-        TraceAnnotation when enabled (device profile rows line up with the
-        host timeline), else a no-op."""
+        """Context manager around a jit call site or a phase of the
+        engine step: a jax profiler TraceAnnotation when enabled (device
+        profile rows line up with the host timeline), else a no-op."""
         if self.trace_annotations:
             from jax.profiler import TraceAnnotation
             return TraceAnnotation(name)

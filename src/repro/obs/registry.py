@@ -52,7 +52,7 @@ class Counter:
 
 
 class Gauge:
-    """Point-in-time value; ``set`` wins, ``add`` for up/down deltas."""
+    """Point-in-time value, replaced by each ``set``."""
 
     __slots__ = ("name", "help", "_value", "_lock")
 
@@ -64,10 +64,6 @@ class Gauge:
     def set(self, v) -> None:
         with self._lock:
             self._value = v
-
-    def add(self, n) -> None:
-        with self._lock:
-            self._value += n
 
     @property
     def value(self):
@@ -190,17 +186,6 @@ class MetricsRegistry:
     def histogram(self, name: str, help: str = "",
                   buckets: tuple = LATENCY_BUCKETS) -> Histogram:
         return self._make(Histogram, name, help, buckets=buckets)
-
-    def get(self, name: str):
-        """Current value of a counter/gauge by name (None if absent)."""
-        # the dict lookup needs the lock (a concurrent _make may be
-        # inserting — dict mutation during .get is only safe for the
-        # built-in path, and the guarded-by rule treats _metrics as owned
-        # by _lock); the value read itself is the instrument's own
-        # lock-free GIL-atomic read
-        with self._lock:
-            m = self._metrics.get(name)
-        return None if m is None else m.value
 
     def snapshot(self) -> dict:
         """Consistent point-in-time copy of every series, JSON-ready."""

@@ -16,7 +16,7 @@ never reads a half-written deque. Capacity is fixed at construction
 from __future__ import annotations
 
 import threading
-from dataclasses import asdict, dataclass, field
+from dataclasses import dataclass, field
 from typing import Optional
 
 
@@ -72,9 +72,6 @@ class StepTimeline:
         """Records oldest-first (a consistent copy)."""
         with self._lock:
             return self._ring[self._head:] + self._ring[:self._head]
-
-    def snapshot_dicts(self) -> list:
-        return [asdict(r) for r in self.snapshot()]
 
     def last(self) -> Optional[StepRecord]:
         with self._lock:

@@ -17,6 +17,8 @@ from __future__ import annotations
 
 import functools
 import os
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -26,10 +28,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.configs.base import dense_stack
+from repro.core.surgery import compress_config
 from repro.kernels import paged_attention as pa
 from repro.models import fused_step, init_params
 from repro.models.paging import init_paged_cache, pages_per_seq
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HBM_BYTES = 16 * 10**9                  # one v5e chip
 # decode widths of mistral-7b: 8 KV heads, 4 query heads each, head_dim 128
 B, KV, REP, HD, MAX_LEN = 8, 8, 4, 128, 1024
@@ -165,3 +169,34 @@ def test_fused_step_sharded_over_model_axis(topo, monkeypatch):
     whole = sum(x.size * x.dtype.itemsize
                 for x in jax.tree.leaves((pshape, cshape)))
     assert 0 < _device_bytes(compiled) < whole / 2
+
+
+def test_fused_step_keeps_the_names_the_benchmark_reads(one_chip,
+                                                        monkeypatch):
+    """A device trace names programs and operations after the compiled
+    HLO. The engine's fused step (its jit wraps a function named
+    ``_fused``) over attention and NBL blocks must keep the module and
+    kernel names the benchmark's patterns match, while the NBL mixer and
+    the MLP carry their layer kind in ``op_name``."""
+    sys.path.insert(0, ROOT)
+    from bench.lib import names
+    _kernel_route(monkeypatch)
+    cfg = compress_config(
+        get_config("mistral-7b", stack=dense_stack(3, window=4096)), [2])
+    args = [_on(a, one_chip) for a in _fused_args(cfg, 64, 1)]
+
+    def _fused(p, t, c, rp, rl, tb):
+        return fused_step(cfg, p, t, c, rp, rl, tb)
+
+    text = jax.jit(  # nbl: disable=jit-discipline -- AOT compile for a described chip, never called
+        _fused, donate_argnums=(2,)).lower(*args).compile().as_text()
+    module = re.search(r"^HloModule ([^,\s]+)", text, re.M).group(1)
+    # a trace names a program "<module>(<program id>)"
+    assert re.search(names.STEP_MODULE, f"{module}(0)"), module
+    kernels = [ln.strip() for ln in text.splitlines()
+               if "tpu_custom_call" in ln]
+    assert kernels and all(re.search(names.PAGED_KERNEL, k)
+                           for k in kernels), [k[:40] for k in kernels]
+    op_names = set(re.findall(r'op_name="([^"]+)"', text))
+    for scope in ("nbl", "mlp"):
+        assert any(f"/{scope}/" in o for o in op_names), scope
