@@ -4,21 +4,29 @@ Single-token decode against the page-pool KV layout of models/paging.py:
 each serving slot's K/V live in fixed-size, position-aligned pages scattered
 through a per-layer pool, addressed by a per-slot page table.
 
-Grid (slot, kv_head, logical_page) with the page sweep innermost. The page
-table and per-slot lengths ride in as SCALAR-PREFETCH operands
-(pltpu.PrefetchScalarGridSpec), so the K/V BlockSpec index_maps read the
-*physical* page id for the current (slot, logical_page) cell and the
-pallas_call machinery DMAs exactly that page HBM->VMEM — the gather IS the
-block indexing, no materialized (B, T) copy. Running max/denominator/output
-accumulator persist in VMEM scratch across the page sweep (online softmax,
-same recurrence as kernels/flash_attention.py).
+Grid (slot, logical_page) with the page sweep innermost; each grid step
+carries every KV head of one page. The page table and per-slot lengths
+ride in as SCALAR-PREFETCH operands (pltpu.PrefetchScalarGridSpec), so the
+K/V BlockSpec index_maps read the *physical* page id for the current
+(slot, logical_page) cell and the pallas_call machinery DMAs exactly that
+page, all heads at once, HBM->VMEM — the gather IS the block indexing, no
+materialized (B, T) copy. The index_map clamps the logical page into the
+row's live span (the pages holding keys the query attends: up to
+lengths[b], and from the window's start where one is set), so steps
+outside it repeat the previous block index and Pallas issues no DMA for
+them; ``pl.when`` skips their compute, and a row of length 0 does no work
+and returns zeros. Running max/denominator/output accumulator for every
+head persist in VMEM scratch across the page sweep (online softmax, same
+recurrence as kernels/flash_attention.py). q and K enter the MXU as
+stored (bf16 on the chip) with float32 accumulation; the probabilities,
+V and the accumulator are float32.
 
 Masking is positional: logical page l covers absolute positions
 [l*page_size, (l+1)*page_size); token t of slot b is valid iff
 t < lengths[b], plus the sliding-window predicate and an allocated-page
-check (unallocated table entries are clamped to page 0 by the index_map and
-killed by the mask). GQA (q heads grouped per kv head), sliding window and
-Gemma-2 logit soft-capping match kernels/flash_attention.py semantics.
+check (steps on unallocated table entries are skipped). GQA (q heads
+grouped per kv head), sliding window and Gemma-2 logit soft-capping match
+kernels/flash_attention.py semantics.
 
 MXU alignment for real TPUs wants head_dim padded to 128 lanes
 (ops.attention-style); interpret mode accepts the tiny test shapes as-is.
@@ -47,11 +55,23 @@ from repro.distributed.api import axis_size, mesh_axes
 NEG_INF = -2.0e38
 
 
+def _live_span(length, page_size: int, window: Optional[int]):
+    """First and last logical page holding a key that the query at
+    ``length - 1`` attends. A row of length 0 has no live page: callers
+    test ``length > 0`` beside the span."""
+    last = (jnp.maximum(length, 1) - 1) // page_size
+    if window is None:
+        return 0, last
+    return jnp.maximum(length - window, 0) // page_size, last
+
+
 def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
             m_scr, l_scr, acc_scr, *, scale: float, window: Optional[int],
             softcap: Optional[float], page_size: int, n_lpages: int):
     b = pl.program_id(0)
-    p = pl.program_id(2)
+    p = pl.program_id(1)
+    length = len_ref[b]                              # valid tokens (pos + 1)
+    first, last = _live_span(length, page_size, window)
 
     @pl.when(p == 0)
     def _init():
@@ -59,35 +79,39 @@ def _kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)              # (rep, hd)
-    k = k_ref[0, 0].astype(jnp.float32)              # (page_size, hd)
-    v = v_ref[0, 0].astype(jnp.float32)
+    # every page in the live span holds at least one unmasked key, so the
+    # running max is finite from the first page on
+    @pl.when((length > 0) & (p >= first) & (p <= last)
+             & (tbl_ref[b, p] >= 0))
+    def _page():
+        q = q_ref[0]                                 # (KV, rep, hd)
+        k = k_ref[0]                                 # (KV, page_size, hd)
+        dt = jnp.promote_types(q.dtype, k.dtype)     # as stored, or wider
+        s = jax.lax.dot_general(
+            q.astype(dt), k.astype(dt), (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale   # (KV, rep, ps)
+        if softcap is not None:
+            s = softcap * jnp.tanh(s / softcap)
+        t = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        mask = t < length
+        if window is not None:
+            mask &= (length - 1 - t) < window
+        s = jnp.where(mask, s, NEG_INF)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-
-    length = len_ref[b]                              # valid tokens (pos + 1)
-    t = p * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = (t < length) & (tbl_ref[b, p] >= 0)
-    if window is not None:
-        mask &= (length - 1 - t) < window
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    pr = jnp.exp(s - m_new[:, None])
-    corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + pr.sum(axis=1)
-    acc_scr[...] = (acc_scr[...] * corr[:, None]
-                    + jax.lax.dot(pr, v, preferred_element_type=jnp.float32))
-    m_scr[...] = m_new
+        m_prev = m_scr[...]                          # (KV, rep, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=2, keepdims=True))
+        pr = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[...] = l_scr[...] * corr + pr.sum(axis=2, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            pr, v_ref[0].astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
 
     @pl.when(p == n_lpages - 1)
     def _finish():
-        denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
-        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_scr[...], 1e-30)
+        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
@@ -99,7 +123,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """q: (B, KV, rep, hd); k_pages/v_pages: (n_pages, KV, page_size, hd);
     page_tbl: (B, n_lpages) int32 physical ids, -1 = unallocated;
     lengths: (B,) int32 valid tokens per slot (query sits at lengths-1).
-    Returns (B, KV, rep, hd)."""
+    Returns (B, KV, rep, hd); a row of length 0 comes back all zero."""
     b, kvh, rep, hd = q.shape
     n_pages, kvh2, page_size, _ = k_pages.shape
     assert kvh == kvh2, (kvh, kvh2)
@@ -110,26 +134,29 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         _kernel, scale=scale, window=window, softcap=softcap,
         page_size=page_size, n_lpages=n_lpages)
 
-    def kv_map(bi, hi, pi, tbl, lens):
-        # physical page for this (slot, logical page); clamp the -1 sentinel
-        # to page 0 — the kernel mask kills those positions.
-        return (jnp.maximum(tbl[bi, pi], 0), hi, 0, 0)
+    def kv_map(bi, pi, tbl, lens):
+        # physical page of the logical page clamped into the row's live
+        # span: steps outside it repeat a neighbour's block index, so no
+        # DMA is issued for them. Empty rows and the -1 sentinel read page
+        # 0; the kernel skips those steps.
+        first, last = _live_span(lens[bi], page_size, window)
+        page = tbl[bi, jnp.minimum(jnp.maximum(pi, first), last)]
+        return (jnp.where(lens[bi] > 0, jnp.maximum(page, 0), 0), 0, 0, 0)
 
+    row = lambda bi, pi, tbl, lens: (bi, 0, 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, n_lpages),
+        grid=(b, n_lpages),
         in_specs=[
-            pl.BlockSpec((1, 1, rep, hd),
-                         lambda bi, hi, pi, tbl, lens: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, hd), kv_map),
-            pl.BlockSpec((1, 1, page_size, hd), kv_map),
+            pl.BlockSpec((1, kvh, rep, hd), row),
+            pl.BlockSpec((1, kvh, page_size, hd), kv_map),
+            pl.BlockSpec((1, kvh, page_size, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, rep, hd),
-                               lambda bi, hi, pi, tbl, lens: (bi, hi, 0, 0)),
+        out_specs=pl.BlockSpec((1, kvh, rep, hd), row),
         scratch_shapes=[
-            pltpu.VMEM((rep,), jnp.float32),          # running max
-            pltpu.VMEM((rep,), jnp.float32),          # running denom
-            pltpu.VMEM((rep, hd), jnp.float32),       # output accumulator
+            pltpu.VMEM((kvh, rep, 1), jnp.float32),     # running max
+            pltpu.VMEM((kvh, rep, 1), jnp.float32),     # running denom
+            pltpu.VMEM((kvh, rep, hd), jnp.float32),    # output accumulator
         ],
     )
     return pl.pallas_call(
@@ -172,10 +199,11 @@ def paged_decode_xla(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     valid = (t < ln) & jnp.repeat(page_tbl >= 0, page_size, axis=1)
     if window is not None:
         valid &= (ln - 1 - t) < window
-    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
+    valid = valid[:, None, None, :]
+    s = jnp.where(valid, s, NEG_INF)
 
     m = s.max(axis=-1, keepdims=True)
-    pr = jnp.exp(s - m)
+    pr = jnp.where(valid, jnp.exp(s - m), 0.0)   # a keyless row gives 0
     denom = jnp.maximum(pr.sum(axis=-1, keepdims=True), 1e-30)
     out = jnp.einsum("bgrt,bgtd->bgrd", (pr / denom),
                      vg.astype(jnp.float32),
@@ -230,10 +258,11 @@ def paged_mixed_xla(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     if window is not None:
         valid &= (qpos[:, :, None] - t) < window
     valid &= qvalid[:, :, None]
-    s = jnp.where(valid[:, None, None, :, :], s, NEG_INF)
+    valid = valid[:, None, None, :, :]
+    s = jnp.where(valid, s, NEG_INF)
 
     m = s.max(axis=-1, keepdims=True)
-    pr = jnp.exp(s - m)
+    pr = jnp.where(valid, jnp.exp(s - m), 0.0)   # a keyless query gives 0
     denom = jnp.maximum(pr.sum(axis=-1, keepdims=True), 1e-30)
     out = jnp.einsum("bgrwt,bgtd->bgrwd", (pr / denom),
                      vg.astype(jnp.float32),
@@ -267,7 +296,8 @@ def paged_mixed(q, k_pages, v_pages, page_tbl, row_pos, row_len, *,
     """Backend dispatcher for the mixed-row step attention: on TPU the
     W queries run as B*W virtual single-token rows through the Mosaic
     ``paged_attention`` kernel (the page sweep's BlockSpec gather keeps
-    that cheap on-device); elsewhere the dense-gather XLA path, whose
+    that cheap on-device, and the invalid rows, of length 0, do no work);
+    elsewhere the dense-gather XLA path, whose
     shared per-slot gather is the fast shape for the serving loop."""
     if use_kernel is None:
         use_kernel = jax.default_backend() == "tpu"

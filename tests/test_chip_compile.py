@@ -102,19 +102,25 @@ def _device_bytes(compiled) -> int:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-@pytest.mark.parametrize("page_size", [64, 8])
-def test_paged_attention_kernel_compiles(one_chip, page_size):
-    """The decode kernel at Mistral widths in bf16, at the engine's page
+@pytest.mark.parametrize("b,kv,rep,hd,page_size,max_len,window", [
+    (B, KV, REP, HD, 64, MAX_LEN, 4096),
+    (B, KV, REP, HD, 8, MAX_LEN, 4096),
+    (8, 36, 1, 64, 64, 2048, None),
+], ids=["64", "8", "minicpm-64"])
+def test_paged_attention_kernel_compiles(one_chip, b, kv, rep, hd, page_size,
+                                         max_len, window):
+    """The decode kernel in bf16: at Mistral widths, at the engine's page
     size and at the server's smaller default (a page block below bf16's
-    16-row sublane tile)."""
-    n_lpages = MAX_LEN // page_size
+    16-row sublane tile); and at MiniCPM-2B widths, 36 KV heads of 64 with
+    one query head each, whose every-head page block is the largest."""
+    n_lpages = max_len // page_size
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
-    pool = (B * n_lpages, KV, page_size, HD)
+    pool = (b * n_lpages, kv, page_size, hd)
     compiled = jax.jit(  # nbl: disable=jit-discipline -- AOT compile for a described chip, never called
-        functools.partial(pa.paged_attention, window=4096)).lower(
-        s((B, KV, REP, HD), jnp.bfloat16), s(pool, jnp.bfloat16),
-        s(pool, jnp.bfloat16), s((B, n_lpages), jnp.int32),
-        s((B,), jnp.int32)).compile()
+        functools.partial(pa.paged_attention, window=window)).lower(
+        s((b, kv, rep, hd), jnp.bfloat16), s(pool, jnp.bfloat16),
+        s(pool, jnp.bfloat16), s((b, n_lpages), jnp.int32),
+        s((b,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -171,16 +177,10 @@ def test_fused_step_sharded_over_model_axis(topo, monkeypatch):
     assert 0 < _device_bytes(compiled) < whole / 2
 
 
-def test_fused_step_keeps_the_names_the_benchmark_reads(one_chip,
-                                                        monkeypatch):
-    """A device trace names programs and operations after the compiled
-    HLO. The engine's fused step (its jit wraps a function named
-    ``_fused``) over attention and NBL blocks must keep the module and
-    kernel names the benchmark's patterns match, while the NBL mixer and
-    the MLP carry their layer kind in ``op_name``."""
-    sys.path.insert(0, ROOT)
-    from bench.lib import names
-    _kernel_route(monkeypatch)
+@pytest.fixture(scope="module")
+def nbl_step_hlo(one_chip):
+    """Compiled HLO of the engine's fused step over two attention layers
+    and one NBL layer, at Mistral widths, with the kernel route on."""
     cfg = compress_config(
         get_config("mistral-7b", stack=dense_stack(3, window=4096)), [2])
     args = [_on(a, one_chip) for a in _fused_args(cfg, 64, 1)]
@@ -188,8 +188,21 @@ def test_fused_step_keeps_the_names_the_benchmark_reads(one_chip,
     def _fused(p, t, c, rp, rl, tb):
         return fused_step(cfg, p, t, c, rp, rl, tb)
 
-    text = jax.jit(  # nbl: disable=jit-discipline -- AOT compile for a described chip, never called
-        _fused, donate_argnums=(2,)).lower(*args).compile().as_text()
+    with pytest.MonkeyPatch.context() as mp:
+        _kernel_route(mp)
+        return jax.jit(  # nbl: disable=jit-discipline -- AOT compile for a described chip, never called
+            _fused, donate_argnums=(2,)).lower(*args).compile().as_text()
+
+
+def test_fused_step_keeps_the_names_the_benchmark_reads(nbl_step_hlo):
+    """A device trace names programs and operations after the compiled
+    HLO. The engine's fused step (its jit wraps a function named
+    ``_fused``) over attention and NBL blocks must keep the module and
+    kernel names the benchmark's patterns match, while the NBL mixer and
+    the MLP carry their layer kind in ``op_name``."""
+    sys.path.insert(0, ROOT)
+    from bench.lib import names
+    text = nbl_step_hlo
     module = re.search(r"^HloModule ([^,\s]+)", text, re.M).group(1)
     # a trace names a program "<module>(<program id>)"
     assert re.search(names.STEP_MODULE, f"{module}(0)"), module
@@ -200,3 +213,24 @@ def test_fused_step_keeps_the_names_the_benchmark_reads(one_chip,
     op_names = set(re.findall(r'op_name="([^"]+)"', text))
     for scope in ("nbl", "mlp"):
         assert any(f"/{scope}/" in o for o in op_names), scope
+
+
+def test_fused_step_has_one_kernel_call_in_the_layer_loop(nbl_step_hlo):
+    """The attention layers run as one loop, and its body makes exactly
+    one Mosaic call, the paged kernel: one kernel call per attention
+    layer per step, which the benchmark's kernel time and call count
+    read."""
+    sys.path.insert(0, ROOT)
+    from bench.lib import names
+    where, comp = [], None
+    for ln in nbl_step_hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%\S+) \(", ln)
+        if head:
+            comp = head.group(1)
+        elif "tpu_custom_call" in ln:
+            where.append((comp, ln.strip()))
+    bodies = set(re.findall(r"body=(%[^,\s]+)", nbl_step_hlo))
+    assert len(where) == 1, [(c, k[:40]) for c, k in where]
+    comp, call = where[0]
+    assert comp in bodies, (comp, bodies)
+    assert re.search(names.PAGED_KERNEL, call), call[:40]

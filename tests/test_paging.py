@@ -43,36 +43,52 @@ def _prompts(cfg, lens, seed=0):
 
 # ----------------------------------------------------- kernel parity -------
 
-@pytest.mark.parametrize("rep,window,softcap", [
-    (1, None, None),       # MHA
-    (2, None, None),       # GQA
-    (2, 6, None),          # GQA + sliding window
-    (2, None, 30.0),       # GQA + logit softcap
-    (2, 6, 30.0),
-])
-def test_paged_kernel_matches_xla_ref(rep, window, softcap):
+_RAGGED = (18, 5, 32, 0)      # mid-page, one page, page-exact, inactive
+
+
+@pytest.mark.parametrize("rep,kv,window,softcap,lens", [
+    (1, 2, None, None, _RAGGED),       # MHA
+    (2, 2, None, None, _RAGGED),       # GQA
+    (2, 2, 6, None, _RAGGED),          # GQA + sliding window
+    (2, 2, None, 30.0, _RAGGED),       # GQA + logit softcap
+    (2, 2, 6, 30.0, _RAGGED),
+    (1, 6, None, None, _RAGGED),       # many KV heads, rep 1 (MiniCPM)
+    (2, 2, 12, None, (29, 32, 20, 14)),  # window starts past page 0
+    (2, 2, None, None, (16, 8, 32, 24)),  # spans end on page boundaries
+    (2, 2, None, None, (32, 0, 32, 0)),   # empty rows beside full ones
+], ids=["1-None-None", "2-None-None", "2-6-None", "2-None-30.0", "2-6-30.0",
+        "kv6-rep1", "window-mid-sequence", "page-boundary", "empty-rows"])
+def test_paged_kernel_matches_xla_ref(rep, kv, window, softcap, lens):
     """Interpret-mode Pallas kernel == XLA gather reference across
-    GQA/window/softcap, with ragged lengths and an inactive slot."""
+    GQA/window/softcap and head counts, with ragged lengths, inactive
+    rows (all-zero output) and, under a window, pages below it
+    unallocated as the engine's window release leaves them."""
     from repro.kernels.paged_attention import paged_attention, paged_decode_xla
 
     rng = np.random.default_rng(0)
-    b, kv, hd, ps, npg, pool = 4, 2, 16, 8, 4, 12
+    b, hd, ps, npg, pool = 4, 16, 8, 4, 16
     q = jnp.asarray(rng.standard_normal((b, kv, rep, hd)), jnp.float32)
     kp = jnp.asarray(rng.standard_normal((pool, kv, ps, hd)), jnp.float32)
     vp = jnp.asarray(rng.standard_normal((pool, kv, ps, hd)), jnp.float32)
     tbl = np.full((b, npg), -1, np.int32)
-    tbl[0, :3] = [4, 7, 1]          # 18 tokens
-    tbl[1, :1] = [2]                # 5 tokens
-    tbl[2, :4] = [0, 3, 5, 6]       # page-exact 32 tokens
-    lens = jnp.asarray([18, 5, 32, 0], jnp.int32)   # slot 3 inactive
+    free = iter([4, 7, 1, 2, 0, 3, 5, 6, 8, 9, 10, 11, 12, 13, 14, 15])
+    for i, n in enumerate(lens):
+        # an inactive row may hold a page: paged_mixed's invalid virtual
+        # rows repeat their slot's table
+        for j in range(max(-(-n // ps), 1)):
+            tbl[i, j] = next(free)
+    if window is not None:
+        tbl[0, :max(lens[0] - window, 0) // ps] = -1   # released below it
+    ln = jnp.asarray(lens, jnp.int32)
 
-    out = paged_attention(q, kp, vp, jnp.asarray(tbl), lens,
+    out = paged_attention(q, kp, vp, jnp.asarray(tbl), ln,
                           window=window, softcap=softcap, interpret=True)
-    ref = paged_decode_xla(q, kp, vp, jnp.asarray(tbl), lens,
+    ref = paged_decode_xla(q, kp, vp, jnp.asarray(tbl), ln,
                            window=window, softcap=softcap)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
     assert np.isfinite(np.asarray(out)).all()
+    assert not np.asarray(out)[np.asarray(lens) == 0].any()
 
 
 @pytest.mark.parametrize("rep,window,softcap", [
