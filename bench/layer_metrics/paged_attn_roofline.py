@@ -1,7 +1,10 @@
 """Kernel: the paged-attention kernel's least time at the chip's peaks
 (operations and bytes of the live KV pages, q and o; the larger of the
 two bounds) over its device time in the trace, per fused step.
-``paged_attn_roofline.chat`` and ``.batch`` share this reader."""
+``paged_attn_roofline.chat`` and ``.batch`` share this reader. The
+trace's kernel time is per chip, and a step sharded over several chips
+gives each its share of the KV heads, so the model-wide least time is
+divided by the chips."""
 from bench.lib import names
 from bench.lib.flops import ModelShape, roofline_s
 from bench.lib.trace import reduce
@@ -21,5 +24,5 @@ def read(ctx, name):
     least = [roofline_s(*shape.paged_attn_cost(ln, ps), peak["bf16_flops"],
                         peak["hbm_bytes_per_s"])[0]
              for ln in obs["dispatches"]]
-    per_step = sum(least) / len(least)
+    per_step = sum(least) / len(least) / ctx["chips"]
     return per_step * red["step_calls"] / red["kernel_s"] * 100.0

@@ -15,14 +15,21 @@ A traffic file (``bench/traffic/<name>.json``) holds only parameters:
     tokens            {"zipf_a", "p_copy"}: the Zipf-Markov token source
     block             size of the fixed sample of draws (see below)
     draw_seed         seed of that fixed sample
+    order             "shuffle" (the default) or "rotate" (see below)
     ramp_s            seconds of load before the measured window opens
     trace_s           length of the profiled sub-window of a traced run
     check_requests    finished requests the output check compares
 
 Every seed gets the same work in another order: ``block`` (prompt, output,
-gap) draws are made once from ``draw_seed``, and each consecutive block of
-requests is a fresh shuffle of them by ``--seed`` (lengths and gaps
-shuffled apart). Token contents come from ``--seed``.
+gap) draws are made once from ``draw_seed``. With ``order`` "shuffle" each
+consecutive block of requests is a fresh shuffle of them by ``--seed``
+(lengths and gaps shuffled apart). With "rotate" the draws keep the order
+they were drawn in and repeat as one cycle, its gaps scaled to span
+exactly ``block / rate_per_s`` seconds, and ``--seed`` picks the draw the
+cycle starts at: any window one cycle long then holds every draw once,
+in the same arrangement, so the seed moves neither the work nor the
+collisions of long prompts with a full batch. Token contents come from
+``--seed``.
 """
 from __future__ import annotations
 
@@ -98,10 +105,15 @@ def plan(traffic: dict, *, vocab: int, seed: int, seconds: float,
     gaps = (base.exponential(1.0 / traffic["rate_per_s"], blk)
             if traffic["loop"] == "open" else np.zeros(blk))
     rng = np.random.default_rng(seed)
-    order_len = np.concatenate([rng.permutation(blk)
-                                for _ in range(-(-n // blk))])[:n]
-    order_gap = np.concatenate([rng.permutation(blk)
-                                for _ in range(-(-n // blk))])[:n]
+    if traffic.get("order", "shuffle") == "rotate":
+        if traffic["loop"] == "open":
+            gaps = gaps * (blk / traffic["rate_per_s"]) / gaps.sum()
+        order_len = order_gap = (int(rng.integers(blk)) + np.arange(n)) % blk
+    else:
+        order_len = np.concatenate([rng.permutation(blk)
+                                    for _ in range(-(-n // blk))])[:n]
+        order_gap = np.concatenate([rng.permutation(blk)
+                                    for _ in range(-(-n // blk))])[:n]
     prompt_len = p_lens[order_len]
     max_new = o_lens[order_len].copy()
     if traffic["loop"] == "closed" and traffic.get("first_wave") == \

@@ -7,7 +7,11 @@ configuration, seed and run settings). It
    and a ``device_kind`` missing from ``bench/peaks.json`` (exit 3);
 2. builds the configuration's compressed model on the device in one jit
    from the seed, then the paged ``Engine``, ``AsyncEngine`` and the TCP
-   front end ``NBLServer``, with the engine settings of the config file;
+   front end ``NBLServer``, with the engine settings of the config file.
+   A cell on more than one chip builds the weights and the engine under a
+   (data, model) = (1, chips) mesh, with the program's own parameter
+   specs, so the engine serves tensor-parallel; on one chip no mesh is
+   made;
 3. warms the fused step and the prefill and page-assign programs of every
    prompt bucket the traffic reaches, with JAX's compile cache at
    ``.jax_cache/`` in the checkout;
@@ -23,6 +27,7 @@ prints goes to stderr.
 """
 from __future__ import annotations
 
+import contextlib
 import gc
 import json
 import os
@@ -165,9 +170,34 @@ def annotate_methods(eng, names, obs) -> None:
         setattr(eng, name, wrapped)
 
 
+def init_weights(mc, seed: int, mesh):
+    """The seeded weights, made on the device in one jit; under a mesh
+    each leaf comes out laid out by the program's own parameter specs."""
+    import jax
+    from repro.models import init_params
+
+    kw = {}
+    if mesh is not None:
+        from repro.distributed.sharding import param_specs
+        from repro.launch.specs import param_shapes
+        kw["out_shardings"] = param_specs(param_shapes(mc))
+    init = jax.jit(lambda k: init_params(k, mc), **kw)  # nbl: disable=jit-discipline -- one call per process
+    return jax.block_until_ready(init(jax.random.PRNGKey(seed)))
+
+
+def bytes_per_device(tree) -> dict:
+    """Bytes of a pytree of arrays held on each device."""
+    import jax
+    out: dict = {}
+    for leaf in jax.tree.leaves(tree):
+        for sh in leaf.addressable_shards:
+            out[sh.device.id] = out.get(sh.device.id, 0) + sh.data.nbytes
+    return out
+
+
 def fused_step_memory(eng) -> dict:
     """What the compiler reports for the engine's decode-step program
-    (bytes; read from the compile cache once warmed)."""
+    (bytes on each device; read from the compile cache once warmed)."""
     import jax.numpy as jnp
     z = jnp.zeros((eng.n_slots,), jnp.int32)
     m = eng._fused_fn(1).lower(
@@ -232,33 +262,41 @@ def main() -> int:
     sys.path.insert(0, os.path.join(ROOT, "src"))
     import numpy as np
     from repro.launch.engine import AsyncEngine, Engine
+    from repro.launch.mesh import make_mesh
     from repro.launch.server import NBLServer
-    from repro.models import init_params
     from repro.obs import Observability
 
     mc = program_config(cfg)
     seed = key_seed(spec["seed"])
-    t = time.monotonic()
-    params = jax.jit(lambda k: init_params(k, mc))(  # nbl: disable=jit-discipline -- one call per process
-        jax.random.PRNGKey(seed))
-    jax.block_until_ready(params)
-    t_init = time.monotonic() - t
+    # several chips serve tensor-parallel; one chip builds no mesh at all
+    mesh = make_mesh((1, spec["chips"]), ("data", "model")) \
+        if spec["chips"] > 1 else None
     e = cfg["engine"]
     obs = make_obs(Observability) if spec["trace"] else None
-    eng = Engine(mc, params, max_len=e["max_len"], n_slots=e["n_slots"],
-                 paged=e["paged"], page_size=e["page_size"],
-                 fused_step=e["fused_step"], obs=obs)
-    del params
-    if obs is not None:
-        obs.engine = eng
-    t = time.monotonic()
-    warm = warm_prompts(eng, spec["prompt_lens"])
-    rng = np.random.default_rng(0)
-    for n in warm:
-        eng.submit(rng.integers(0, mc.vocab_size, n), 2, strict=True)
-    eng.run()
-    t_warm = time.monotonic() - t
-    step_mem = fused_step_memory(eng)
+    # the engine keeps the mesh it is built under and steps under it
+    with jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext():
+        t = time.monotonic()
+        params = init_weights(mc, seed, mesh)
+        t_init = time.monotonic() - t
+        held = bytes_per_device(params)
+        eng = Engine(mc, params, max_len=e["max_len"], n_slots=e["n_slots"],
+                     paged=e["paged"], page_size=e["page_size"],
+                     fused_step=e["fused_step"], obs=obs)
+        del params
+        if obs is not None:
+            obs.engine = eng
+        t = time.monotonic()
+        warm = warm_prompts(eng, spec["prompt_lens"])
+        rng = np.random.default_rng(0)
+        for n in warm:
+            eng.submit(rng.integers(0, mc.vocab_size, n), 2, strict=True)
+        eng.run()
+        t_warm = time.monotonic() - t
+        step_mem = fused_step_memory(eng)
+    print(f"serve_cell: weights spread over {len(held)} device(s), "
+          f"{max(held.values()) / 1e9:.3f} GB on the fullest; mesh "
+          f"{dict(mesh.shape) if mesh is not None else None}",
+          file=sys.stderr)
     if spec["trace"]:
         annotate_methods(eng, ("step", "_plan_admission", "_commit_fused"),
                          obs)

@@ -78,3 +78,26 @@ def test_closed_first_wave_is_residual():
     # the residual spreads the first wave's ends from the window's start
     assert min(first) < 100 and sorted(first) != first
     assert all(r["t"] is None for r in p)
+
+
+def test_rotated_cycle_holds_every_draw_once():
+    t = load("chat-poisson")
+    assert t["order"] == "rotate"
+    blk, cycle = t["block"], t["block"] / t["rate_per_s"]
+    plans = [tgen.plan(t, vocab=1000, seed=s, seconds=50, n_slots=48)
+             for s in (1, 2, 2**40 + 5)]
+    starts = {(len(p[0]["prompt"]), p[0]["max_new"]) for p in plans}
+    assert len(starts) > 1                    # the seed moves the start
+    work = set()
+    for p in plans:
+        t_ = np.array([r["t"] for r in p])
+        assert t_[blk] - t_[0] == pytest.approx(cycle)
+        for w0 in (t["ramp_s"], 33.3):        # any window one cycle long
+            inw = [r for r, x in zip(p, t_) if w0 <= x < w0 + cycle]
+            assert len(inw) == blk
+            work.add((tuple(sorted(len(r["prompt"]) for r in inw)),
+                      tuple(sorted(r["max_new"] for r in inw))))
+        # the same arrangement: request k + blk repeats request k
+        assert [(len(a["prompt"]), a["max_new"]) for a in p[:blk]] == \
+            [(len(b["prompt"]), b["max_new"]) for b in p[blk:2 * blk]]
+    assert len(work) == 1
