@@ -243,12 +243,22 @@ from repro.launch.speculative import (
 )
 from repro.models.paging import (
     DEFAULT_PAGE_SIZE, PageAllocator, PrefixIndex, assign_pages,
-    build_page_table, init_paged_cache, n_caching_attn_layers,
-    pages_per_seq, pool_pages_for_budget, pow2_ceil, release_tail_pages,
-    span_pages,
+    build_page_table, init_paged_cache, kv_pool_bytes_per_device,
+    n_caching_attn_layers, pages_per_seq, pool_pages_for_budget, pow2_ceil,
+    release_tail_pages, span_pages,
 )
 
 _NULLCTX = nullcontext()     # shared no-op ctx for un-annotated jit calls
+
+
+def _new_cache(make):
+    """The engine's cache from ``make()``. Under a mesh it comes out of
+    one jit already laid out by ``cache_specs``, so no device ever holds a
+    whole pool; without one it is ``make()`` itself."""
+    if not mesh_axes():
+        return make()
+    specs = cache_specs(jax.eval_shape(make))
+    return jax.jit(make, out_shardings=specs)()  # nbl: disable=jit-discipline -- one call per engine
 
 
 # The shared jit cache for UNSHARDED engines lives in repro.jitcache (so
@@ -465,12 +475,13 @@ class Engine:
                                                 range(self.n_slots)]
             self.prefix_index = PrefixIndex(self.page_size) \
                 if self.prefix_sharing else None
-            self.cache = init_paged_cache(cfg, self.n_slots, self.max_len,
-                                          page_size=self.page_size,
-                                          n_pages=self.n_pages)
+            self.cache = _new_cache(lambda: init_paged_cache(
+                cfg, self.n_slots, self.max_len, page_size=self.page_size,
+                n_pages=self.n_pages))
         else:
             self.n_pages = 0
-            self.cache = init_slot_cache(cfg, self.n_slots, self.max_len)
+            self.cache = _new_cache(lambda: init_slot_cache(
+                cfg, self.n_slots, self.max_len))
         self.slot_req: list[Optional[Request]] = [None] * self.n_slots
         self.slot_pos = np.zeros(self.n_slots, np.int32)   # pos of last tok
         self.slot_tok = np.zeros(self.n_slots, np.int32)   # last emitted tok
@@ -535,6 +546,7 @@ class Engine:
             obs.bind(engine_mode=self.mode_name,
                      nbl_m=sum(1 for b in blocks if b.kind == "nbl"))
             obs.g_slots.set(self.n_slots)
+            obs.g_kv_pool.set(kv_pool_bytes_per_device(self.cache))
 
         sharded = bool(mesh_axes())
         pspecs = param_specs(jax.eval_shape(lambda: params)) \
@@ -543,10 +555,9 @@ class Engine:
             if sharded else None
         if sharded:
             # the jits below pin their inputs to these layouts, and jit
-            # refuses a committed array laid out otherwise (the cache was
-            # just built replicated on the mesh): place both once here
+            # refuses a committed array laid out otherwise: place the
+            # params once here (the cache is born in its layout)
             self.params = jax.device_put(params, pspecs)
-            self.cache = jax.device_put(self.cache, cspecs)
 
         dkw = dict(donate_argnums=(2,)) if donate else {}
         akw = dict(donate_argnums=(0,)) if donate else {}

@@ -167,6 +167,18 @@ def init_paged_cache(cfg: ModelConfig, n_slots: int, max_len: int, *,
     return {"groups": groups}
 
 
+def kv_pool_bytes_per_device(cache) -> int:
+    """Bytes of the K and V page pools on the device that holds the most
+    of them (0 for a cache without pools). Read from the arrays' shards,
+    so a pool sharded over a mesh counts each device's part."""
+    held: dict = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        if str(getattr(path[-1], "key", "")) in ("k_pages", "v_pages"):
+            for sh in leaf.addressable_shards:
+                held[sh.device] = held.get(sh.device, 0) + sh.data.nbytes
+    return max(held.values(), default=0)
+
+
 def assign_pages(cfg: ModelConfig, paged_cache, prefill_cache, slot,
                  page_ids, *, page_size: int):
     """Write a batch=1 POSITION-ALIGNED prefill cache into the page pools.

@@ -140,6 +140,9 @@ class Observability:
         self.g_slots = r.gauge("nbl_slots_total", "engine slot count")
         self.g_pages_used = r.gauge("nbl_pages_in_use", "allocator occupancy")
         self.g_pages_free = r.gauge("nbl_pages_free", "allocator free pages")
+        self.g_kv_pool = r.gauge(
+            "nbl_kv_pool_bytes_per_device",
+            "KV page-pool bytes on the fullest device, set at engine build")
         self.g_prefix = r.gauge("nbl_prefix_index_entries",
                                 "PrefixIndex published pages")
         self.g_budget_util = r.gauge(

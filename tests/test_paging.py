@@ -931,3 +931,121 @@ def test_paged_stats_fields():
     assert s["n"] == 2 and s["n_pages"] == eng.n_pages
     assert 0.0 < s["pool_utilization"] <= 1.0
     assert s["pages_in_use"] == 0 and s["n_preemptions"] == 0
+
+
+# ------------------------------------------- the pool under a mesh -------
+
+# the tensor-parallel layout at a tiny size: an attention run then an NBL
+# run, as many KV heads as chips, so "model" = 4 splits heads and pools
+_TP4 = """
+import warnings; warnings.filterwarnings('ignore')
+import jax, numpy as np
+from repro.configs import get_config
+from repro.configs.base import dense_stack
+from repro.core.surgery import compress_config
+from repro.launch.mesh import make_mesh
+from repro.models import init_params
+cfg = compress_config(get_config('tiny-dense', stack=dense_stack(4),
+                                 n_kv_heads=4), [3])
+params = init_params(jax.random.PRNGKey(0), cfg)
+mesh = make_mesh((1, 4), ('data', 'model'))
+KW = dict(max_len=96, n_slots=4, paged=True, page_size=8)
+"""
+# one pool (3 layers x 48 pages x 4 heads x 8 x 16 float32) whole, and the
+# quarter of it each device holds
+_POOL_BYTES, _POOL_QUARTER = 294_912, 73_728
+
+
+def test_sharded_engine_builds_each_pool_a_quarter_per_device(subproc):
+    """Under a (1, 4) mesh no device ever holds a whole pool: every pool
+    the engine builds is born sharded, and the gauge reads the fullest
+    device's K and V bytes."""
+    out = subproc(_TP4 + """
+import repro.launch.engine as E
+from repro.obs import Observability
+held = []
+_init = E.init_paged_cache
+def spy(*a, **kw):
+    c = _init(*a, **kw)
+    for leaf in jax.tree.leaves(c):
+        if not isinstance(leaf, jax.core.Tracer):   # built outside a jit
+            held.append(max(s.data.nbytes for s in leaf.addressable_shards))
+    return c
+E.init_paged_cache = spy
+obs = Observability()
+with jax.set_mesh(mesh):
+    eng = E.Engine(cfg, params, obs=obs, **KW)
+pools = [b for g in eng.cache['groups'] for b in g['blocks'] if b]
+per_dev = {}
+for blk in pools:
+    for leaf in (blk['k_pages'], blk['v_pages']):
+        for s in leaf.addressable_shards:
+            per_dev[s.device.id] = per_dev.get(s.device.id, 0) + s.data.nbytes
+        print('POOL', leaf.nbytes, sorted({s.data.nbytes
+                                           for s in leaf.addressable_shards}))
+print('HELD', max(held, default=0))
+print('DEVICES', len(per_dev), max(per_dev.values()))
+print('GAUGE', obs.g_kv_pool.value)
+""", n_devices=4)
+    lines = dict(ln.split(" ", 1) for ln in out.splitlines()
+                 if ln.split(" ")[0] in ("HELD", "DEVICES", "GAUGE"))
+    pools = [ln for ln in out.splitlines() if ln.startswith("POOL")]
+    assert pools == [f"POOL {_POOL_BYTES} [{_POOL_QUARTER}]"] * 2, out
+    # the parent's path built each pool whole on every device first
+    assert int(lines["HELD"]) == 0, out
+    assert lines["DEVICES"] == f"4 {2 * _POOL_QUARTER}", out
+    assert int(lines["GAUGE"]) == 2 * _POOL_QUARTER, out
+
+
+def test_kv_pool_gauge_without_a_mesh():
+    """Unsharded, the gauge reads both whole pools; the ring layout has
+    none."""
+    from repro.obs import Observability
+    cfg, params = _setup()
+    obs = Observability()
+    eng = Engine(cfg, params, max_len=16, n_slots=2, paged=True,
+                 page_size=8, obs=obs)
+    pools = [leaf for g in eng.cache["groups"] for b in g["blocks"]
+             for k, leaf in b.items() if k in ("k_pages", "v_pages")]
+    assert pools and obs.g_kv_pool.value == sum(p.nbytes for p in pools)
+    ring = Observability()
+    Engine(cfg, params, max_len=16, n_slots=2, obs=ring)
+    assert ring.g_kv_pool.value == 0
+
+
+def test_sharded_nbl_engine_logits_match_unsharded(subproc):
+    """The tp4 layout served sharded (the paged kernel in its shard_map)
+    picks every token from logits that match the unsharded engine's."""
+    out = subproc(_TP4 + """
+import functools
+from repro.kernels import paged_attention as pa
+from repro.launch.engine import Engine
+pa.paged_attention = functools.partial(pa.paged_attention, interpret=True)
+pa.paged_mixed = functools.partial(pa.paged_mixed, use_kernel=True)
+rows = []
+_sample = Engine._sample
+def spy(self, row):
+    rows[-1].append(np.array(row))
+    return _sample(self, row)
+Engine._sample = spy
+rng = np.random.default_rng(0)
+prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+           for n in (9, 17, 30)]
+outs = []
+for mesh_ in (None, mesh):
+    rows.append([])
+    if mesh_ is None:
+        eng = Engine(cfg, params, **KW)
+    else:
+        with jax.set_mesh(mesh_):
+            eng = Engine(cfg, params, **KW)
+    rids = [eng.submit(p, 6) for p in prompts]
+    res = eng.run()
+    outs.append([list(res[r]) for r in rids])
+assert outs[0] == outs[1], outs
+assert len(rows[0]) == len(rows[1]) == 18
+err = max(float(np.abs(a - b).max()) for a, b in zip(*rows))
+assert err < 1e-4, err
+print('OK', err)
+""", n_devices=4)
+    assert "OK" in out
